@@ -19,6 +19,7 @@ import tempfile
 
 _CHILD = r"""
 import os, sys, json
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import jax
 from repro.launch.mesh import make_production_mesh

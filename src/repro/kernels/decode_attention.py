@@ -2,17 +2,23 @@
 
 One new query token per sequence attends to its full cached context. The
 grid is ``(batch, S/block_k)`` with the KV dimension innermost (sequential
-on TPU); all heads of one sequence are processed together so the MXU sees
-an [H, Dp] x [Dp, block_k] matmul per step instead of H rank-1 products.
+on TPU); all heads of one sequence are processed together as one batched
+``[H, 1, Dp] x [H, Dp, block_k]`` matmul per step.
+
+The wrapper lays K/V out head-major (``[B, H, S, Dp]``) so that each block's
+last two dims are ``(block_k, Dp)``, the (8, 128)-aligned tile Mosaic needs.
+``lengths`` (valid cache slots per sequence, [B] int32) is a scalar-prefetch
+operand: it sits in SMEM before the grid starts, so both the kernel body and
+the K/V index maps can read it.
 
 BlockSpec tiling (per grid step, all VMEM):
-    q       : (1, H, Dp)
-    k/v     : (1, block_k, H, Dp)
-    lengths : (1, 1) int32        -- valid cache slots for this sequence
-    out     : (1, H, Dp)
-    scratch : acc (H, Dp) f32, m/l (H, 128) f32 (lane-broadcast)
+    q       : (1, H, 1, Dp)
+    k/v     : (1, H, block_k, Dp)
+    out     : (1, H, 1, Dp)
+    scratch : acc (H, 1, Dp) f32, m/l (H, 1, 128) f32 (lane-broadcast)
 
-Blocks entirely beyond ``lengths[b]`` are compute-skipped.
+Blocks entirely beyond ``lengths[b]`` are compute-skipped, and their K/V
+index maps clamp to the last valid block so no new tile is fetched for them.
 """
 from __future__ import annotations
 
@@ -57,11 +63,12 @@ def decode_attention_cost(n_seqs: int, n_heads: int, head_dim: int,
 _NEG_INF = -1e30
 
 
-def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, acc_ref, m_ref, l_ref, *,
+def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale: float, block_k: int):
+    b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
-    length = len_ref[0, 0]
+    length = len_ref[b]
 
     @pl.when(j == 0)
     def _init():
@@ -71,38 +78,36 @@ def _kernel(q_ref, k_ref, v_ref, len_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
     @pl.when(j * block_k < length)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)                    # [H, Dp]
-        k = k_ref[0].astype(jnp.float32)                    # [bk, H, Dp]
+        q = q_ref[0].astype(jnp.float32)                    # [H, 1, Dp]
+        k = k_ref[0].astype(jnp.float32)                    # [H, bk, Dp]
         v = v_ref[0].astype(jnp.float32)
         H = q.shape[0]
-        # [H, bk] logits: contract Dp, batch over H
+        # [H, 1, bk] logits: contract Dp, batch over H
         s = jax.lax.dot_general(
-            q, jnp.swapaxes(k, 0, 1),                        # [H,Dp] x [H,bk,Dp]
-            (((1,), (2,)), ((0,), (0,))),
+            q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale
         kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (H, block_k), 1)
+            jnp.int32, (H, 1, block_k), 2)
         mask = kpos < length
         s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
+        m_prev = m_ref[:, :, :1]                             # [H, 1, 1]
+        l_prev = l_ref[:, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)         # [H, bk]
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)         # [H, 1, bk]
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = jnp.broadcast_to(
             alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        # [H, Dp] update: contract bk, batch over H
+        # [H, 1, Dp] update: contract bk, batch over H
         pv = jax.lax.dot_general(
-            p, jnp.swapaxes(v, 0, 1),                        # [H,bk] x [H,bk,Dp]
-            (((1,), (1,)), ((0,), (0,))),
+            p, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
         o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
@@ -119,34 +124,38 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     pad_d = (-D) % 128
     pad_s = (-S) % block_k
-    if pad_d:
-        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_d)))
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad_d)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad_d)))
-    if pad_s:
-        k = jnp.pad(k, ((0, 0), (0, pad_s), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_s), (0, 0), (0, 0)))
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, pad_d)))[:, :, None, :]
+    k = jnp.pad(jnp.swapaxes(k, 1, 2),
+                ((0, 0), (0, 0), (0, pad_s), (0, pad_d)))
+    v = jnp.pad(jnp.swapaxes(v, 1, 2),
+                ((0, 0), (0, 0), (0, pad_s), (0, pad_d)))
     Sp, Dp = S + pad_s, D + pad_d
-    len2 = lengths.astype(jnp.int32).reshape(B, 1)
+
+    def kv_block(b, j, lens):
+        # blocks past the valid length re-address the last valid block, so
+        # the pipeline skips their fetch (same block index as the step before)
+        last = jnp.maximum(lens[b] - 1, 0) // block_k
+        return (b, 0, jnp.minimum(j, last), 0)
 
     kernel = functools.partial(_kernel, scale=scale, block_k=block_k)
     out = pl.pallas_call(
         kernel,
-        grid=(B, Sp // block_k),
-        in_specs=[
-            pl.BlockSpec((1, H, Dp), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, H, Dp), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, block_k, H, Dp), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, 1), lambda b, j: (b, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((1, H, Dp), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, Dp), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((H, Dp), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, Sp // block_k),
+            in_specs=[
+                pl.BlockSpec((1, H, 1, Dp), lambda b, j, lens: (b, 0, 0, 0)),
+                pl.BlockSpec((1, H, block_k, Dp), kv_block),
+                pl.BlockSpec((1, H, block_k, Dp), kv_block),
+            ],
+            out_specs=pl.BlockSpec((1, H, 1, Dp),
+                                   lambda b, j, lens: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, 1, Dp), jnp.float32),
+                pltpu.VMEM((H, 1, 128), jnp.float32),
+                pltpu.VMEM((H, 1, 128), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, Dp), q.dtype),
         interpret=interpret,
-    )(q, k, v, len2)
-    return out[:, :, :D]
+    )(lengths.astype(jnp.int32), q, k, v)
+    return out[:, :, 0, :D]

@@ -2,11 +2,15 @@
 otherwise.
 
 Selection order:
-  1. ``REPRO_USE_PALLAS=1`` (or running on a real TPU backend) -> pallas_call
+  1. a TPU backend (or ``REPRO_USE_PALLAS=1``) -> compiled pallas_call
      kernels with BlockSpec VMEM tiling;
   2. ``REPRO_PALLAS_INTERPRET=1`` -> same kernels, interpret mode (CPU CI);
   3. otherwise -> the pure-jnp reference (ref.py), which XLA fuses well and
      which the dry-run lowers through.
+
+On a TPU backend the compiled kernels are the only choice: asking there for
+the reference (``REPRO_USE_PALLAS=0``) or for interpret mode raises, so a
+chip run never silently measures something else.
 """
 from __future__ import annotations
 
@@ -24,18 +28,24 @@ __all__ = ["attention", "decode_attention", "ssd", "rglru", "use_pallas",
 
 
 def use_pallas() -> bool:
-    if os.environ.get("REPRO_USE_PALLAS") == "1":
+    """Compiled Pallas kernels: always on a TPU backend, elsewhere only with
+    ``REPRO_USE_PALLAS=1``."""
+    flag = os.environ.get("REPRO_USE_PALLAS")
+    if jax.default_backend() == "tpu":
+        if flag == "0":
+            raise RuntimeError("REPRO_USE_PALLAS=0 on a TPU backend: the "
+                               "chip path always runs the Pallas kernels")
         return True
-    if os.environ.get("REPRO_USE_PALLAS") == "0":
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return flag == "1"
 
 
 def interpret_mode() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET") == "1"
+    if os.environ.get("REPRO_PALLAS_INTERPRET") != "1":
+        return False
+    if jax.default_backend() == "tpu":
+        raise RuntimeError("REPRO_PALLAS_INTERPRET=1 on a TPU backend: the "
+                           "chip path runs the compiled kernels")
+    return True
 
 
 def _pallas_enabled() -> bool:

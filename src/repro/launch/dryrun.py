@@ -1,9 +1,10 @@
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-MUST be imported/run before anything else initialises jax: the first two
-lines pin 512 placeholder host devices so ``jax.make_mesh`` can build the
-production meshes. Do NOT set this env var anywhere global — smoke tests
-and benches see 1 device.
+MUST be imported/run before anything else initialises jax: the first lines
+pin the CPU backend with 512 placeholder host devices so ``jax.make_mesh``
+can build the production meshes (and a machine with a TPU never hands its
+chip to this compile-only run). Do NOT set these env vars anywhere global —
+smoke tests and benches see 1 device.
 
 Per cell this entrypoint records:
   * compile success,
@@ -31,6 +32,9 @@ import traceback
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
+# the package has imported jax already (so JAX_PLATFORMS is read); no
+# backend has started yet, so the config still pins it
+jax.config.update("jax_platforms", "cpu")
 import jax.numpy as jnp
 import numpy as np
 

@@ -1,16 +1,19 @@
 """Serving launcher: disaggregated P/D cluster with MFS-scheduled transfers.
 
-Runs the real JAX engine (reduced config on CPU; full config on a pod) under
-the DisaggServer orchestrator and reports per-request TTFT / SLO attainment
-per scheduling policy.
+Runs the real JAX engine (the reduced SMOKE config by default; the published
+widths with ``--full``) under the DisaggServer orchestrator and reports
+per-request TTFT / SLO attainment per scheduling policy. TTFT is read off
+the virtual clock, priced by the peak table of the chip the process runs on
+(off a chip: TPU v5e's).
 
 Usage:
     PYTHONPATH=src python -m repro.launch.serve --arch smollm-360m \
-        --requests 16 --rps 200 --policy mfs [--policy fs ...]
+        --requests 16 --rps 200 --policy mfs [--policy fs ...] [--full]
 """
 from __future__ import annotations
 
 import argparse
+from typing import Optional
 
 import jax
 import numpy as np
@@ -19,6 +22,8 @@ from ..configs import ARCHS, SMOKES
 from ..core import make_policy
 from ..models.lm import build_model
 from ..serving import DisaggConfig, DisaggServer, ServeRequest
+from ..simcluster.hw import HW, TPU_V5E
+from .cache import enable_compile_cache
 
 __all__ = ["make_requests", "run"]
 
@@ -50,15 +55,15 @@ def make_requests(cfg, n: int, rps: float, seed: int = 0,
 
 def run(arch: str, *, smoke: bool = True, n_requests: int = 16,
         rps: float = 200.0, policies=("mfs",), seed: int = 0,
-        n_units: int = 2, verbose: bool = True):
+        n_units: int = 2, verbose: bool = True, hw: Optional[HW] = None):
     cfg = (SMOKES if smoke else ARCHS)[arch]
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(seed))
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
     reqs = make_requests(cfg, n_requests, rps, seed)
     summary = {}
     for pol in policies:
         srv = DisaggServer(model, params, policy=make_policy(pol),
-                           cfg=DisaggConfig(n_prefill_units=n_units))
+                           cfg=DisaggConfig(n_prefill_units=n_units, hw=hw))
         res = srv.serve(reqs)
         slo = sum(r.met_slo for r in res) / len(res)
         mean_ttft = float(np.mean([r.ttft for r in res]))
@@ -76,15 +81,19 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m")
     ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="the published widths instead of the SMOKE preset")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--rps", type=float, default=200.0)
     ap.add_argument("--policy", action="append", default=None)
     ap.add_argument("--units", type=int, default=2)
     ap.add_argument("--seed", type=int, default=0)
     a = ap.parse_args()
+    enable_compile_cache()
+    on_chip = jax.default_backend() == "tpu"
     run(a.arch, smoke=a.smoke, n_requests=a.requests, rps=a.rps,
         policies=tuple(a.policy or ["mfs", "fs", "sjf", "edf", "karuna"]),
-        seed=a.seed, n_units=a.units)
+        seed=a.seed, n_units=a.units, hw=None if on_chip else TPU_V5E)
 
 
 if __name__ == "__main__":

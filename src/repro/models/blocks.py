@@ -23,13 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-# ``jax.shard_map`` graduated from jax.experimental in newer releases; fall
-# back to the experimental entry point (same signature) on older installs.
-try:
-    _shard_map = jax.shard_map
-except AttributeError:                                    # jax < 0.6
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..configs.base import ArchConfig
 from .layers import (DEFAULT_DTYPE, apply_rope, dense, gqa_attention,
                      init_dense, rmsnorm, rmsnorm_params, rope, swiglu,
@@ -116,6 +109,21 @@ def _grouped_ok(cfg: ArchConfig, dims: AttnDims, n_store: int) -> bool:
     real_rep = max(1, cfg.n_heads // max(1, cfg.n_kv))
     return all(min(h // real_rep, n_store - 1) == h // rep
                for h in range(dims.n_q))
+
+
+def _per_head(ctx: ShardCtx, fn, *xs):
+    """Run a [B, T, H, D] attention kernel on each device's own heads (and
+    batch rows). Mosaic kernels cannot be partitioned by the compiler, so
+    over a mesh the call goes through ``shard_map``; heads are independent,
+    so the result is the same as one call over all of them."""
+    if ctx.mesh is None:
+        return fn(*xs)
+    batch = ctx.pspec("batch")[0] if xs[0].shape[0] % ctx.data_size == 0 \
+        else None
+    spec = P(batch, None, ctx.model_axis, None)
+    # check_vma=False: pallas_call declares no per-axis variance
+    return jax.shard_map(fn, mesh=ctx.mesh, in_specs=(spec,) * len(xs),
+                         out_specs=spec, check_vma=False)(*xs)
 
 
 def attn_init(key, cfg: ArchConfig, ctx: ShardCtx, dtype=DEFAULT_DTYPE):
@@ -248,8 +256,9 @@ def attn_apply(p, x, *, cfg: ArchConfig, ctx: ShardCtx, mode: str,
         out = gqa_attention(q, k_all, v_all, mask=mask)
     else:
         q_off = q_offset if (cache is not None and mode == "prefill") else 0
-        out = kops.attention(q, k_all, v_all, causal=(mode != "encode"),
-                             window=window, q_offset=q_off)
+        out = _per_head(ctx, partial(kops.attention, causal=(mode != "encode"),
+                                     window=window, q_offset=q_off),
+                        q, k_all, v_all)
     out = ctx.act(out, ("batch", None, "model", None))
     y = dense(p["wo"], out.reshape(B, T, dims.n_q * dims.hd))
     return ctx.act(y, ("batch", None, None)), new_cache
@@ -425,18 +434,12 @@ def _moe_local(p, x, cfg: ArchConfig):
     return out.reshape(B, T, D)   # shared experts are added by moe_apply
 
 
-def _one_axis_size(a: str) -> int:
-    if hasattr(jax.lax, "axis_size"):          # jax >= 0.6
-        return jax.lax.axis_size(a)
-    return jax.lax.psum(1, a)                  # classic spelling
-
-
 def _axis_size(axis) -> int:
     if isinstance(axis, str):
-        return _one_axis_size(axis)
+        return jax.lax.axis_size(axis)
     n = 1
     for a in axis:
-        n *= _one_axis_size(a)
+        n *= jax.lax.axis_size(a)
     return n
 
 
@@ -446,7 +449,7 @@ def _axis_index(axis):
         return jax.lax.axis_index(axis)
     idx = 0
     for a in axis:
-        idx = idx * _one_axis_size(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 
@@ -526,7 +529,7 @@ def moe_apply(p, x, *, cfg: ArchConfig, ctx: ShardCtx,
                                capacity_factor=capacity_factor)
             return out.reshape(xl.shape)
 
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(batch, ctx.model_axis, None),
                       P(), expert_spec, expert_spec, expert_spec),
@@ -565,7 +568,7 @@ def moe_apply(p, x, *, cfg: ArchConfig, ctx: ShardCtx,
             out = jax.lax.psum(out, ctx.ep_axes)            # Stage-2 combine
             return out.reshape(xl.shape)
 
-        mapped = _shard_map(
+        mapped = jax.shard_map(
             body_dec, mesh=ctx.mesh,
             in_specs=(P(dec_batch, None, None),
                       P(), expert_spec, expert_spec, expert_spec),

@@ -41,7 +41,7 @@ delivered, exactly as the simulator charges it.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, replace
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -61,7 +61,7 @@ from ..core.telemetry import Telemetry, TelemetrySpec
 from ..netsim.events import EventQueue
 from ..netsim.fluid import FluidNet
 from ..netsim.topology import SingleToR
-from ..simcluster.hw import HW, TPU_V5E
+from ..simcluster.hw import HW, hw_for_device
 from .engine import DecodeBatch, ServingEngine
 from .paged_kv import PagedStore, PrefixIndex, cache_has_state
 
@@ -97,12 +97,17 @@ class ServeResult:
     tpot: float = 0.0               # mean modeled time per output token
     tpot_ok: bool = True
     migrations: int = 0
+    # --- data plane outcomes the caller must be able to see ---
+    prefix_registered: bool = False  # False: page pool full, not reusable
+    decode_admitted: bool = False    # False: decode slots full, no decode
 
 
 @dataclass(frozen=True)
 class DisaggConfig:
     n_prefill_units: int = 2
-    hw: HW = TPU_V5E
+    # peaks that price the virtual clock; None = the chip this process runs
+    # on (``hw_for_device``), which raises off a known chip
+    hw: Optional[HW] = None
     layer_groups: int = 4           # P2D / promotion granularity
     slo_scale: float = 3.0          # SLO = scale x contention-free TTFT (§6.1)
     page_size: int = 16
@@ -158,6 +163,8 @@ class DisaggServer(RuntimeHost):
                  cfg: DisaggConfig = DisaggConfig()):
         self.model = model
         self.params = params
+        if cfg.hw is None:
+            cfg = replace(cfg, hw=hw_for_device(jax.devices()[0].device_kind))
         self.cfg = cfg
         self.policy = policy if policy is not None else MFSScheduler()
         self.policy.reset()
@@ -225,8 +232,10 @@ class DisaggServer(RuntimeHost):
             admission=rspec.build_admission() if rspec is not None else None,
             telemetry=self.telemetry, monitor=self.monitor)
 
-        self.engines = [ServingEngine(model, params)
-                        for _ in range(cfg.n_prefill_units)]
+        # one engine (one set of jitted prefills) serves every prefill unit:
+        # the units differ only on the virtual clock, so each shape compiles
+        # once per server rather than once per unit
+        self.engine = ServingEngine(model, params)
         self.decoder = DecodeBatch(model, params, capacity=cfg.decode_capacity,
                                    max_slots=cfg.decode_slots)
         self.store = PagedStore(cfg.page_size, cfg.n_pages)
@@ -295,7 +304,7 @@ class DisaggServer(RuntimeHost):
         for it in bs.items:
             job: _ServeJob = it.payload
             prefix_cache = self._prefix_cache_for(job.entry, it.reuse)
-            first, cache, _ = self.engines[bs.unit].prefill(
+            first, cache, _ = self.engine.prefill(
                 job.req.tokens, prefix_cache=prefix_cache,
                 prefix_len=it.reuse if prefix_cache is not None else 0,
                 extra=job.req.extra)
@@ -346,17 +355,21 @@ class DisaggServer(RuntimeHost):
         # register the prefix for future reuse + hand off to the decode unit
         if cache_has_state(job.cache):
             self.index.insert_snapshot(r.tokens, job.cache, item.unit)
+            res.prefix_registered = True
         else:
             try:
                 pages = self.store.put(job.cache, len(r.tokens))
+            except MemoryError:
+                pages = None                # pool full: reported, not reused
+            if pages is not None:
                 self.index.insert_paged(r.tokens, pages, item.unit,
                                         self._kv_bytes_per_token())
                 self.store.release(pages)   # index holds its own references
-            except MemoryError:
-                pass                         # pool full: skip registration
+                res.prefix_registered = True
         if self.decoder.n_active < self.cfg.decode_slots:
             self.decoder.add(r.rid, job.cache, len(r.tokens),
                              job.first_token, max_new=r.max_new)
+            res.decode_admitted = True
         job.cache = None
 
     def on_decode_admitted(self, sess: DecodeSession) -> None:

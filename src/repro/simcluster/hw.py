@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-__all__ = ["HW", "A100", "TPU_V5E", "RTX3090"]
+__all__ = ["HW", "A100", "TPU_V5E", "RTX3090", "DEVICE_HW", "hw_for_device"]
 
 GB = 1e9
 Gb = 1e9 / 8
@@ -37,6 +37,24 @@ A100 = HW("a100", flops=312e12, hbm_bw=2039 * GB, nic_bw=200 * Gb,
 RTX3090 = HW("rtx3090", flops=71e12, hbm_bw=936 * GB, nic_bw=50 * Gb,
              scaleup_bw=16 * GB, mfu=0.35)
 
-# Roofline target hardware (per brief): TPU v5e.
+# TPU v5e, one chip. flops and hbm_bw are the published peaks (Google Cloud
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s).
+# nic_bw / scaleup_bw take one of the chip's four ICI links (1,600 Gbit/s
+# in all, same source); mfu is this model's assumption, not a measurement.
 TPU_V5E = HW("tpu_v5e", flops=197e12, hbm_bw=819 * GB, nic_bw=50 * GB,
              scaleup_bw=50 * GB, mfu=0.5)
+
+#: Peak table for runs on a chip, keyed by ``jax.Device.device_kind``.
+DEVICE_HW = {"TPU v5 lite": TPU_V5E}
+
+
+def hw_for_device(device_kind: str) -> HW:
+    """The peak table of the chip a run is on. A kind missing from
+    ``DEVICE_HW`` is an error: pass ``HW`` explicitly to model another
+    target (as CPU runs must)."""
+    try:
+        return DEVICE_HW[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device kind {device_kind!r} "
+                       f"(known: {sorted(DEVICE_HW)}); pass hw explicitly"
+                       ) from None

@@ -10,6 +10,7 @@ Checks, on a (2 data x 4 model) CPU mesh:
 Exit code 0 = all pass.
 """
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", ""))
@@ -22,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import SMOKES
+from repro.launch.mesh import auto_mesh
 from repro.models.lm import build_model
 from repro.models.sharding import ShardCtx
 
@@ -40,7 +42,7 @@ def _check(name, a, b, tol=TOL):
 
 def main() -> int:
     assert jax.device_count() == 8, jax.device_count()
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = auto_mesh((2, 4), ("data", "model"))
     rng = np.random.default_rng(0)
     key = jax.random.PRNGKey(0)
     ok = True

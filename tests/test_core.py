@@ -5,7 +5,7 @@ import math
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (BatchLoad, Flow, MLUConfig, RMLQ, Stage,
                         geometric_thresholds, inter_request_schedule, mlu,

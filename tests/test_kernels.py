@@ -267,3 +267,17 @@ def test_decode_step_time_calibrated_against_kernel_roofline():
             # padding and attention flops only ever ADD work
             assert r >= a * (1 - 1e-9)
     assert max(errs) < 0.15, f"decode model error {max(errs):.3f}"
+
+
+@pytest.mark.parametrize("env", [{"REPRO_USE_PALLAS": "0"},
+                                 {"REPRO_PALLAS_INTERPRET": "1"}])
+def test_tpu_backend_refuses_kernel_switches(monkeypatch, env):
+    """On a TPU backend the compiled kernels are the only path: a switch to
+    the jnp reference or to interpret mode raises instead of taking it."""
+    from repro.kernels import ops
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    q = jnp.zeros((1, 8, 1, 64), jnp.float32)
+    with pytest.raises(RuntimeError):
+        ops.attention(q, q, q)

@@ -3,7 +3,7 @@ rate caps, conservation; event queue determinism."""
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Stage, new_flow_id
 from repro.core.msflow import Flow

@@ -12,6 +12,7 @@ from repro.models.lm import build_model
 from repro.serving import (DecodeBatch, DisaggConfig, DisaggServer,
                            PagedStore, PrefixIndex, ServeRequest,
                            ServingEngine, cache_has_state)
+from repro.simcluster.hw import TPU_V5E
 
 KEY = jax.random.PRNGKey(0)
 
@@ -162,7 +163,8 @@ def test_disagg_server_end_to_end(smollm):
         reqs.append(ServeRequest(rid=i, arrival=i * 1e-4, tokens=toks,
                                  max_new=3))
     srv = DisaggServer(model, params,
-                       cfg=DisaggConfig(n_prefill_units=2, n_pages=128))
+                       cfg=DisaggConfig(n_prefill_units=2, n_pages=128,
+                                        hw=TPU_V5E))
     res = srv.serve(reqs)
     assert len(res) == 6
     assert all(r.ttft > 0 for r in res)
@@ -184,10 +186,12 @@ def test_disagg_reuse_is_exact(smollm):
     sfx = rng.integers(0, cfg.vocab, size=(8,))
     toks = np.concatenate([shared, sfx])
     cold = DisaggServer(model, params,
-                        cfg=DisaggConfig(n_prefill_units=1, n_pages=64))
+                        cfg=DisaggConfig(n_prefill_units=1, n_pages=64,
+                                         hw=TPU_V5E))
     r_cold = cold.serve([ServeRequest(0, 0.0, toks, max_new=1)])[0]
     warm = DisaggServer(model, params,
-                        cfg=DisaggConfig(n_prefill_units=1, n_pages=64))
+                        cfg=DisaggConfig(n_prefill_units=1, n_pages=64,
+                                         hw=TPU_V5E))
     warm.serve([ServeRequest(0, 0.0, np.concatenate(
         [shared, rng.integers(0, cfg.vocab, size=(6,))]), max_new=1)])
     r_warm = warm.serve([ServeRequest(1, 1.0, toks, max_new=1)])[0]
@@ -204,7 +208,7 @@ def test_disagg_policies_all_run(smollm):
             for i in range(4)]
     for pol in ("mfs", "fs", "sjf", "edf", "karuna"):
         srv = DisaggServer(model, params, policy=make_policy(pol),
-                           cfg=DisaggConfig(n_prefill_units=2))
+                           cfg=DisaggConfig(n_prefill_units=2, hw=TPU_V5E))
         res = srv.serve(reqs)
         assert len(res) == 4
 
@@ -260,3 +264,33 @@ def test_chunked_disagg_reuse_is_exact(smollm):
     ])
     assert res[1].reused_tokens == 24          # page-aligned prefix hit
     assert res[1].first_token == want.first_token
+
+
+def test_disagg_reports_skipped_registration_and_decode(smollm):
+    """A full page pool and full decode slots are reported per request,
+    not swallowed."""
+    cfg, model, params = smollm
+    rng = np.random.default_rng(9)
+    reqs = [ServeRequest(i, i * 1e-4, rng.integers(0, cfg.vocab, size=(40,)),
+                         max_new=2) for i in range(3)]
+    srv = DisaggServer(model, params, cfg=DisaggConfig(
+        n_prefill_units=1, n_pages=4, page_size=16, decode_slots=2,
+        hw=TPU_V5E))
+    res = srv.serve(reqs)
+    # 40 tokens take 3 pages and the index keeps the 2 full ones: after the
+    # first request 2 of the 4 pages are free, too few for the next
+    assert [r.prefix_registered for r in res] == [True, False, False]
+    assert [r.decode_admitted for r in res] == [True, True, False]
+    assert [len(r.tokens) for r in res] == [2, 2, 1]
+
+
+def test_disagg_hw_comes_from_the_device(smollm):
+    """Without an explicit HW the server prices its clock with the peak
+    table of the chip it runs on; off a known chip that is an error."""
+    from repro.simcluster.hw import hw_for_device
+    cfg, model, params = smollm
+    assert hw_for_device("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError):
+        hw_for_device("TPU v99")
+    with pytest.raises(KeyError):
+        DisaggServer(model, params, cfg=DisaggConfig(n_prefill_units=1))

@@ -11,6 +11,7 @@ from repro.configs import ARCHS, SHAPES, SMOKES
 from repro.core import make_policy
 from repro.launch.serve import make_requests, run as serve_run
 from repro.launch.specs import SKIP_REASONS, input_specs, plan_cells
+from repro.simcluster.hw import TPU_V5E
 
 
 def test_assigned_matrix_is_complete():
@@ -47,7 +48,7 @@ def test_input_specs_all_cells():
 @pytest.mark.slow
 def test_serve_launcher_policies_end_to_end():
     summary = serve_run("smollm-360m", n_requests=6, rps=500.0,
-                        policies=("mfs", "fs"), verbose=False)
+                        policies=("mfs", "fs"), verbose=False, hw=TPU_V5E)
     assert set(summary) == {"mfs", "fs"}
     for s in summary.values():
         assert 0.0 <= s["slo_attainment"] <= 1.0
