@@ -42,6 +42,7 @@ import numpy as np                                           # noqa: E402
 
 from repro.configs import ARCHS, SMOKES                      # noqa: E402
 from repro.core import make_policy                           # noqa: E402
+from repro.core.telemetry import wall_spans                  # noqa: E402
 from repro.kernels import ops                                # noqa: E402
 from repro.launch.cache import enable_compile_cache          # noqa: E402
 from repro.models.lm import build_model                      # noqa: E402
@@ -55,29 +56,15 @@ LOGIT_TOL = 3e-2
 MOE_TOL = 6e-2              # capacity-dropped tokens may differ slightly
 
 
-class CompileCounter:
-    """Counts XLA compiles and persistent-cache hits via jax.monitoring."""
-
-    def __init__(self):
-        self.requests = 0          # backend compiles, cache hits included
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.requests += 1
-            self.seconds += secs
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def line(self) -> str:
-        return (f"compiles: {self.requests - self.cache_hits} xla compiles +"
-                f" {self.cache_hits} persistent-cache hits,"
-                f" {self.seconds:.1f} s in compile calls")
+def compile_line() -> str:
+    """The process's XLA compiles, from the program's per-entry counter."""
+    c = wall_spans.compiles
+    t = c.total()
+    entries = ", ".join(f"{n} {c.entry(n).compiles}" for n in
+                        ("prefill_full", "prefill_suffix", "decode_step"))
+    return (f"compiles: {t.compiles - t.cache_hits} xla compiles +"
+            f" {t.cache_hits} persistent-cache hits,"
+            f" {t.seconds:.1f} s in compile calls; by entry: {entries}")
 
 
 def rel_err(a, b) -> float:
@@ -262,7 +249,6 @@ def main(argv=None) -> int:
               file=sys.stderr)
         return 1
     cache_dir = enable_compile_cache()
-    counter = CompileCounter()
     dev = devs[0]
     print(f"device: {dev.platform} {dev.device_kind!r} x {len(devs)};"
           f" compile cache {cache_dir}", flush=True)
@@ -272,7 +258,7 @@ def main(argv=None) -> int:
         failed = sharded_phase(ARCHS[ARCH], log=log)
     else:
         failed = serve_phase(ARCHS[ARCH], log=log)
-    log(counter.line())
+    log(compile_line())
     stats = dev.memory_stats() or {}
     log(f"peak_bytes_in_use (device 0): {stats.get('peak_bytes_in_use')}")
     if failed:

@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import itertools
 from collections import deque
+from time import perf_counter_ns
 from typing import Deque, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -58,7 +59,7 @@ from .router import (AdmissionController, KVAffinityRouter, RouterPolicy,
                      RoutingView)
 from .stages import (BatchState, ChunkPlan, PrefillItem, StageEmitter,
                      StageProfile)
-from .telemetry import StageLog, Telemetry
+from .telemetry import StageLog, Telemetry, wall_spans
 
 __all__ = ["RuntimeHost", "MsFlowRuntime", "RuntimeView"]
 
@@ -829,36 +830,49 @@ class MsFlowRuntime:
 
     # ------------------------------------------------------------------ run
     def run(self, max_events: int = 5_000_000) -> None:
-        """Drain the event queue (arrivals must already be pushed)."""
-        n_ev = 0
-        while self.evq and n_ev < max_events:
-            popped = self.evq.pop()
-            if popped is None:
-                break
-            t, kind, payload, epoch = popped
-            n_ev += 1
-            if self._probe is not None:
-                # BEFORE advance: current rates are exactly the rates active
-                # over [net.now, t], so span/link integration here is exact
-                self._probe.on_advance(self.net, t)
-            done = self.net.advance(t)
-            for f in done:
-                self._on_flow_done(f)
-            if kind == "arr":
-                self._on_arrival(payload)
-                self._resched(("submit",))
-            elif kind == "compute":
-                self._on_compute_done(*payload)
-            elif kind == "tick":
-                self._on_tick()
-            elif kind == "dstep":
-                if self.decode is not None \
-                        and self.decode.on_step(payload, t):
-                    self._resched(("submit",))   # rebalancer emitted D2D
-                    self._arm_tick()
-            elif kind == "net":
-                if done:
-                    self._resched(("event",))
-                elif epoch == self._epoch:
-                    # numerically-stalled prediction; force refresh
-                    self._resched(("event",))
+        """Drain the event queue (arrivals must already be pushed).
+
+        While a profiler session records wall-clock spans, the drain is
+        the span ``repro.runtime.run``; its args count the events of each
+        kind (``arr``, ``compute``, ``tick``, ``dstep``, ``net``) and the
+        host time spent on them (``<kind>_ns``), callbacks included."""
+        with wall_spans.span("repro.runtime.run") as span:
+            timed = span.on
+            n_ev = 0
+            while self.evq and n_ev < max_events:
+                if timed:
+                    t_ns = perf_counter_ns()
+                popped = self.evq.pop()
+                if popped is None:
+                    break
+                t, kind, payload, epoch = popped
+                n_ev += 1
+                if self._probe is not None:
+                    # BEFORE advance: current rates are exactly the rates
+                    # active over [net.now, t], so span/link integration
+                    # here is exact
+                    self._probe.on_advance(self.net, t)
+                done = self.net.advance(t)
+                for f in done:
+                    self._on_flow_done(f)
+                if kind == "arr":
+                    self._on_arrival(payload)
+                    self._resched(("submit",))
+                elif kind == "compute":
+                    self._on_compute_done(*payload)
+                elif kind == "tick":
+                    self._on_tick()
+                elif kind == "dstep":
+                    if self.decode is not None \
+                            and self.decode.on_step(payload, t):
+                        self._resched(("submit",))   # rebalancer emitted D2D
+                        self._arm_tick()
+                elif kind == "net":
+                    if done:
+                        self._resched(("event",))
+                    elif epoch == self._epoch:
+                        # numerically-stalled prediction; force refresh
+                        self._resched(("event",))
+                if timed:
+                    span.add(kind, 1)
+                    span.add(kind + "_ns", perf_counter_ns() - t_ns)

@@ -52,21 +52,42 @@ Analysis + export:
   * :meth:`Telemetry.to_chrome_trace` — Chrome/Perfetto trace-event JSON,
     so a sweep run renders as an inspectable timeline.
 
+Wall-clock spans (:data:`wall_spans`), beside the event-clock collector:
+
+  * :class:`WallSpans` — a process-wide channel of host spans on
+    ``time.perf_counter_ns()``: name, start, end, the enclosing span, the
+    request id and a few counts. It records exactly while a profiler
+    session is active; outside one a probe site costs one check. While
+    recording, each span also enters the profiler's own host annotation of
+    the same name, so an operator who takes a ``jax.profiler`` trace gets
+    the serving path's spans beside the device ops, on the trace's clock.
+    Bounded; drops are counted. A pure observer: served tokens are the
+    same with a session on and off.
+  * :class:`CompileCounter` — XLA compiles and persistent-cache hits per
+    jitted entry (``wall_spans.compiles``), counted always.
+
+The serving layer installs the profiler gate, the annotation and the
+compile events (``repro.serving.engine``).
+
 Control-plane only (no JAX), host-agnostic like the rest of ``repro.core``.
 """
 from __future__ import annotations
 
+import itertools
 import json
+import threading
 import warnings
 from collections import deque
 from dataclasses import dataclass, field
-from typing import (Any, Callable, Dict, Iterable, List, Optional, Set,
-                    Tuple)
+from time import perf_counter_ns
+from typing import (Any, Callable, ContextManager, Dict, Iterable, List,
+                    Optional, Set, Tuple)
 
 from .msflow import Flow, FlowState, Stage
 
 __all__ = ["TelemetrySpec", "Telemetry", "StageLog", "FlowSpan",
-           "RequestTrace", "link_name"]
+           "RequestTrace", "link_name", "WallSpan", "WallSpans",
+           "CompileCounter", "EntryCompiles", "wall_spans"]
 
 
 # --------------------------------------------------------------------- spec
@@ -748,3 +769,171 @@ class Telemetry:
             "links_sampled": len(self.link_byte_time),
             "dropped": dict(self.dropped),
         }
+
+
+# --------------------------------------------------------- wall-clock spans
+class WallSpan:
+    """One span of the wall-clock channel, and the context manager that
+    records it. ``start_ns`` / ``end_ns`` are ``time.perf_counter_ns()``;
+    ``sid`` numbers spans in the order they opened; ``parent`` is the
+    ``sid`` of the span open around this one on the same thread (-1 at the
+    top); ``args`` holds a few counts, set while the span is open."""
+
+    __slots__ = ("name", "rid", "args", "sid", "parent", "start_ns",
+                 "end_ns", "_ch", "_ann")
+    on = True
+
+    def __init__(self, ch: "WallSpans", name: str, rid: Optional[int],
+                 args: Dict[str, int]):
+        self.name, self.rid, self.args = name, rid, args
+        self.sid = self.parent = -1
+        self.start_ns = self.end_ns = 0
+        self._ch, self._ann = ch, None
+
+    def set(self, **args: int) -> None:
+        self.args.update(args)
+
+    def add(self, key: str, n: int) -> None:
+        self.args[key] = self.args.get(key, 0) + n
+
+    @property
+    def duration_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+    def __enter__(self) -> "WallSpan":
+        ch = self._ch
+        stack = ch._stack()
+        self.sid = next(ch._sids)
+        self.parent = stack[-1].sid if stack else -1
+        stack.append(self)
+        ch._keep(self)
+        if ch.annotate is not None:
+            self._ann = ch.annotate(self.name)
+            self._ann.__enter__()
+        self.start_ns = perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end_ns = perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self._ch._stack().pop()
+        self._ch = self._ann = None
+        return False
+
+
+class _Off:
+    """What a probe site gets outside a profiler session: records nothing."""
+
+    on = False
+
+    def __enter__(self) -> "_Off":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **args: int) -> None:
+        pass
+
+    def add(self, key: str, n: int) -> None:
+        pass
+
+
+_OFF = _Off()
+
+
+@dataclass
+class EntryCompiles:
+    compiles: int = 0          # compile calls, persistent-cache hits too
+    cache_hits: int = 0
+    seconds: float = 0.0       # spent in the compile calls
+
+
+class CompileCounter:
+    """XLA compiles and persistent-cache hits per jitted entry, named by
+    the function ``jax.jit`` was given (``prefill_full``, ``decode_step``).
+    A cache hit is reported inside the compile call it serves, so it is
+    held until that call's duration names the entry."""
+
+    def __init__(self):
+        self.by_entry: Dict[str, EntryCompiles] = {}
+        self._hits = 0
+
+    def cache_hit(self) -> None:
+        self._hits += 1
+
+    def compiled(self, fun_name: str, seconds: float) -> None:
+        name = fun_name[4:-1] if fun_name.startswith("jit(") \
+            and fun_name.endswith(")") else fun_name
+        e = self.by_entry.setdefault(name, EntryCompiles())
+        e.compiles += 1
+        e.seconds += seconds
+        e.cache_hits += self._hits
+        self._hits = 0
+
+    def entry(self, name: str) -> EntryCompiles:
+        return self.by_entry.get(name, EntryCompiles())
+
+    def total(self) -> EntryCompiles:
+        t = EntryCompiles()
+        for e in self.by_entry.values():
+            t.compiles += e.compiles
+            t.cache_hits += e.cache_hits
+            t.seconds += e.seconds
+        return t
+
+
+def _never() -> bool:
+    return False
+
+
+class WallSpans:
+    """The process-wide wall-clock span channel (see module docstring).
+
+    ``span(name, rid=None, **args)`` is the probe: a context manager that
+    records a :class:`WallSpan` while ``recording()`` holds, and does
+    nothing otherwise. ``spans`` keeps the newest ``limit`` spans in the
+    order they opened; ``dropped`` counts the older ones it let go, as
+    :class:`StageLog` does. ``clear()`` empties it between profiles."""
+
+    def __init__(self, limit: int = 1 << 16):
+        self.limit = limit
+        self.spans: deque = deque(maxlen=limit)
+        self.dropped = 0
+        self.recording: Callable[[], bool] = _never
+        self.annotate: Optional[Callable[[str], ContextManager]] = None
+        self.compiles = CompileCounter()
+        self._sids = itertools.count()
+        self._local = threading.local()
+
+    def install(self, recording: Callable[[], bool],
+                annotate: Optional[Callable[[str], ContextManager]]) -> None:
+        """Gate the channel on ``recording`` and mirror each span into
+        ``annotate(name)``, the profiler's host annotation."""
+        self.recording, self.annotate = recording, annotate
+
+    def span(self, name: str, rid: Optional[int] = None, **args: int):
+        if not self.recording():
+            return _OFF
+        return WallSpan(self, name, rid, args)
+
+    def clear(self) -> None:
+        self.spans.clear()
+        self.dropped = 0
+
+    def _stack(self) -> List[WallSpan]:
+        st = getattr(self._local, "stack", None)
+        if st is None:
+            st = self._local.stack = []
+        return st
+
+    def _keep(self, sp: WallSpan) -> None:
+        if len(self.spans) == self.limit:
+            self.dropped += 1
+        self.spans.append(sp)
+
+
+#: the one channel of the process (like ``jax.monitoring``, it outlives the
+#: servers that record into it)
+wall_spans = WallSpans()

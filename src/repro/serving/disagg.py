@@ -57,7 +57,7 @@ from ..core.runtime import MsFlowRuntime, RuntimeHost
 from ..core.stages import (BatchState, ChunkSpec, GroupPlan, ParallelismSpec,
                            PrefillItem, StageEmitter, StageProfile)
 from ..core.monitor import Monitor, MonitorSpec
-from ..core.telemetry import Telemetry, TelemetrySpec
+from ..core.telemetry import Telemetry, TelemetrySpec, wall_spans
 from ..netsim.events import EventQueue
 from ..netsim.fluid import FluidNet
 from ..netsim.topology import SingleToR
@@ -266,7 +266,9 @@ class DisaggServer(RuntimeHost):
         real pages for the modeled hit.
         """
         job: _ServeJob = item.payload
-        entry = self.index.match(job.req.tokens)
+        with wall_spans.span("repro.kv.match", rid=job.req.rid) as span:
+            entry = self.index.match(job.req.tokens)
+            span.set(matched=entry.n_tokens if entry else 0)
         if self.kvstore is not None:
             job.entry = entry
             return
@@ -303,11 +305,17 @@ class DisaggServer(RuntimeHost):
         # later pruned — only the clock pays the recompute penalty then.
         for it in bs.items:
             job: _ServeJob = it.payload
-            prefix_cache = self._prefix_cache_for(job.entry, it.reuse)
-            first, cache, _ = self.engine.prefill(
-                job.req.tokens, prefix_cache=prefix_cache,
-                prefix_len=it.reuse if prefix_cache is not None else 0,
-                extra=job.req.extra)
+            rid = job.req.rid
+            with wall_spans.span("repro.kv.gather", rid=rid) as span:
+                prefix_cache = self._prefix_cache_for(job.entry, it.reuse)
+                reused = it.reuse if prefix_cache is not None else 0
+                span.set(reused=reused)
+            with wall_spans.span("repro.prefill", rid=rid,
+                                 computed=len(job.req.tokens) - reused,
+                                 reused=reused):
+                first, cache, _ = self.engine.prefill(
+                    job.req.tokens, prefix_cache=prefix_cache,
+                    prefix_len=reused, extra=job.req.extra)
             job.first_token = first
             job.cache = cache
 
@@ -353,22 +361,28 @@ class DisaggServer(RuntimeHost):
             pruned=r.rid in self.runtime.ever_pruned)
         self.results[r.rid] = res
         # register the prefix for future reuse + hand off to the decode unit
-        if cache_has_state(job.cache):
-            self.index.insert_snapshot(r.tokens, job.cache, item.unit)
-            res.prefix_registered = True
-        else:
-            try:
-                pages = self.store.put(job.cache, len(r.tokens))
-            except MemoryError:
-                pages = None                # pool full: reported, not reused
-            if pages is not None:
-                self.index.insert_paged(r.tokens, pages, item.unit,
-                                        self._kv_bytes_per_token())
-                self.store.release(pages)   # index holds its own references
+        with wall_spans.span("repro.kv.register", rid=r.rid) as span:
+            if cache_has_state(job.cache):
+                self.index.insert_snapshot(r.tokens, job.cache, item.unit)
                 res.prefix_registered = True
+                span.set(pages=0, pool_full=0)
+            else:
+                try:
+                    pages = self.store.put(job.cache, len(r.tokens))
+                except MemoryError:
+                    pages = None            # pool full: reported, not reused
+                if pages is not None:
+                    self.index.insert_paged(r.tokens, pages, item.unit,
+                                            self._kv_bytes_per_token())
+                    self.store.release(pages)   # the index holds its own
+                    res.prefix_registered = True
+                span.set(pages=len(pages) if pages else 0,
+                         pool_full=int(pages is None))
         if self.decoder.n_active < self.cfg.decode_slots:
-            self.decoder.add(r.rid, job.cache, len(r.tokens),
-                             job.first_token, max_new=r.max_new)
+            with wall_spans.span("repro.decode.admit", rid=r.rid) as span:
+                slot = self.decoder.add(r.rid, job.cache, len(r.tokens),
+                                        job.first_token, max_new=r.max_new)
+                span.set(slot=slot)
             res.decode_admitted = True
         job.cache = None
 
@@ -387,16 +401,22 @@ class DisaggServer(RuntimeHost):
     # --------------------------------------------------------------- serving
     def serve(self, requests: Sequence[ServeRequest],
               decode_steps: int = 4) -> List[ServeResult]:
-        for r in sorted(requests, key=lambda x: x.arrival):
-            self.runtime.push_arrival(PrefillItem(
-                rid=r.rid, arrival=r.arrival, n_tokens=len(r.tokens),
-                slo_class=r.slo_class, out_tokens=r.max_new,
-                payload=_ServeJob(req=r)))
-        self.runtime.run()
-        # all prefills finished: run the decode continuation (real tokens)
-        for _ in range(decode_steps):
-            if not self.decoder.n_active:
-                break
-            for rid, tok in self.decoder.step().items():
-                self.results[rid].tokens.append(tok)
+        """Admit ``requests``, run the runtime until every prefill is done,
+        then up to ``decode_steps`` decode steps. Under a profiler session
+        the call is the wall-clock span ``repro.serve`` (see
+        ``repro.core.telemetry.WallSpans``), with the runtime's drain, the
+        prefix index, prefills, KV registration and decode steps inside."""
+        with wall_spans.span("repro.serve", requests=len(requests)):
+            for r in sorted(requests, key=lambda x: x.arrival):
+                self.runtime.push_arrival(PrefillItem(
+                    rid=r.rid, arrival=r.arrival, n_tokens=len(r.tokens),
+                    slo_class=r.slo_class, out_tokens=r.max_new,
+                    payload=_ServeJob(req=r)))
+            self.runtime.run()
+            # all prefills finished: run the decode continuation (real tokens)
+            for _ in range(decode_steps):
+                if not self.decoder.n_active:
+                    break
+                for rid, tok in self.decoder.step().items():
+                    self.results[rid].tokens.append(tok)
         return [self.results[r.rid] for r in requests]

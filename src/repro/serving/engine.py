@@ -9,6 +9,11 @@ what lets sequences of different lengths share a batch (continuous
 batching). Slots are recycled as sequences retire; inactive slots still
 compute (dead lanes) and are masked out of the results, exactly as a
 fixed-shape TPU serving binary would.
+
+The jitted entries are named ``prefill_full``, ``prefill_suffix`` and
+``decode_step``: a profile's XLA modules and the per-entry compile counter
+(``wall_spans.compiles``) say which program compiled. Importing this module
+gates the wall-clock span channel on the profiler (``_install_wall_spans``).
 """
 from __future__ import annotations
 
@@ -20,10 +25,38 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.telemetry import wall_spans
 from ..models.lm import Model
 from .paged_kv import is_token_leaf_path
 
 __all__ = ["ServingEngine", "DecodeBatch"]
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _install_wall_spans() -> None:
+    """Record the channel's spans exactly while a profiler session is active,
+    each also as a ``TraceAnnotation`` of the same name (so it lands in the
+    profile on the trace's clock), and feed its compile counter from
+    ``jax.monitoring``."""
+    ann = jax.profiler.TraceAnnotation
+    wall_spans.install(ann.is_enabled, ann)
+    counter = wall_spans.compiles
+
+    def on_duration(event, secs, fun_name="", **_):
+        if event == _BACKEND_COMPILE:
+            counter.compiled(fun_name, secs)
+
+    def on_event(event, **_):
+        if event == _CACHE_HIT:
+            counter.cache_hit()
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.monitoring.register_event_listener(on_event)
+
+
+_install_wall_spans()
 
 
 class ServingEngine:
@@ -32,10 +65,15 @@ class ServingEngine:
     def __init__(self, model: Model, params: Any):
         self.model = model
         self.params = params
-        self._full = jax.jit(lambda p, b: model.prefill(p, b))
-        self._suffix = jax.jit(
-            lambda p, b, caches, pos: model.prefill(p, b, caches=caches,
-                                                    pos=pos))
+
+        def prefill_full(p, b):
+            return model.prefill(p, b)
+
+        def prefill_suffix(p, b, caches, pos):
+            return model.prefill(p, b, caches=caches, pos=pos)
+
+        self._full = jax.jit(prefill_full)
+        self._suffix = jax.jit(prefill_suffix)
 
     def prefill(self, tokens: np.ndarray,
                 prefix_cache: Optional[Any] = None,
@@ -116,14 +154,14 @@ class DecodeBatch:
         self._stacked = jax.tree_util.tree_map_with_path(expand, example_cache)
         model = self.model
 
-        def one(p, cache, tok, pos):
+        def decode_step(p, cache, tok, pos):
             # vmap strips the B axis (axis 1); run the model at B=1 inside
             cache = jax.tree.map(lambda x: x[:, None], cache)
             logits, new_cache = model.decode_step(p, cache, tok, pos)
             return logits, jax.tree.map(lambda x: x[:, 0], new_cache)
 
         self._step_fn = jax.jit(jax.vmap(
-            one, in_axes=(None, 1, 0, 0), out_axes=(0, 1)))
+            decode_step, in_axes=(None, 1, 0, 0), out_axes=(0, 1)))
 
     # ------------------------------------------------------------ lifecycle
     def add(self, rid: int, cache: Any, n_tokens: int, first_token: int,
@@ -172,19 +210,27 @@ class DecodeBatch:
         and retires slots that reached ``max_new`` or capacity."""
         if not self.slots:
             return {}
-        logits, self._stacked = self._step_fn(
-            self.params, self._stacked, self._tok, self._pos)
-        nxt = jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
+        live = len(self.slots)
+        # spans: the launch, the wait for its tokens (the loop's first int()
+        # would block on the same value), and the host loop over live slots
+        with wall_spans.span("repro.decode.launch", live=live):
+            logits, self._stacked = self._step_fn(
+                self.params, self._stacked, self._tok, self._pos)
+            nxt = jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
+        with wall_spans.span("repro.decode.wait"):
+            nxt.block_until_ready()
         out: Dict[int, int] = {}
-        for slot, meta in list(self.slots.items()):
-            t = int(nxt[slot])
-            meta.tokens.append(t)
-            meta.pos += 1
-            out[meta.rid] = t
-            self._tok = self._tok.at[slot, 0, 0].set(t)
-            self._pos = self._pos.at[slot].set(meta.pos)
-            if len(meta.tokens) >= meta.max_new or meta.pos >= self.capacity - 1:
-                self.remove(slot)
+        with wall_spans.span("repro.decode.slots", live=live):
+            for slot, meta in list(self.slots.items()):
+                t = int(nxt[slot])
+                meta.tokens.append(t)
+                meta.pos += 1
+                out[meta.rid] = t
+                self._tok = self._tok.at[slot, 0, 0].set(t)
+                self._pos = self._pos.at[slot].set(meta.pos)
+                if len(meta.tokens) >= meta.max_new \
+                        or meta.pos >= self.capacity - 1:
+                    self.remove(slot)
         return out
 
     @property

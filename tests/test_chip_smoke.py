@@ -20,6 +20,13 @@ def test_serve_phase_passes_at_smoke_size(monkeypatch):
         hw=TPU_V5E, on_chip=False, log=lines.append)
     assert failed == [], "\n".join(lines)
     assert any("reuse vs recompute" in ln for ln in lines)
+    # the compiles line reads the program's per-entry counter
+    line = chip_smoke.compile_line()
+    assert line.startswith("compiles: ")
+    by_entry = dict(x.rsplit(" ", 1) for x in
+                    line.split("by entry: ")[1].split(", "))
+    assert int(by_entry["prefill_full"]) >= 1
+    assert int(by_entry["decode_step"]) >= 1
 
 
 @pytest.mark.parametrize("argv", [[], ["--chips", "4"]])
