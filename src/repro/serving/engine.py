@@ -10,10 +10,20 @@ batching). Slots are recycled as sequences retire; inactive slots still
 compute (dead lanes) and are masked out of the results, exactly as a
 fixed-shape TPU serving binary would.
 
-The jitted entries are named ``prefill_full``, ``prefill_suffix`` and
-``decode_step``: a profile's XLA modules and the per-entry compile counter
-(``wall_spans.compiles``) say which program compiled. Importing this module
-gates the wall-clock span channel on the profiler (``_install_wall_spans``).
+The step's inputs ``tok`` ([max_slots, 1, 1]) and ``pos`` ([max_slots])
+live on the host as int32 NumPy arrays: ``add`` and the bookkeeping after a
+step write them there, and each step uploads both in one explicit
+``jax.device_put``, uncommitted to the default device as every other input
+of the step is (a committed input would commit the step's outputs, and the
+stacked cache's first write after a step would compile anew). Each step
+then reads the device once, the ``[max_slots]`` greedy next tokens, whatever
+the number of live slots. Dead lanes keep their last token and position.
+
+The jitted entries are named ``prefill_full``, ``prefill_suffix``,
+``decode_step`` and ``decode_argmax``: a profile's XLA modules and the
+per-entry compile counter (``wall_spans.compiles``) say which program
+compiled. Importing this module gates the wall-clock span channel on the
+profiler (``_install_wall_spans``).
 """
 from __future__ import annotations
 
@@ -102,6 +112,13 @@ class ServingEngine:
         return first, cache, logits
 
 
+@jax.jit
+def decode_argmax(logits):
+    """Greedy next token of each slot. Jitted: one dispatch a step, where
+    eager slicing would also upload its index scalars."""
+    return jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
+
+
 @dataclass
 class _Slot:
     rid: int
@@ -122,8 +139,8 @@ class DecodeBatch:
         self.slots: Dict[int, _Slot] = {}
         self._free = list(range(max_slots - 1, -1, -1))
         self._stacked: Optional[Any] = None
-        self._tok = jnp.zeros((max_slots, 1, 1), jnp.int32)
-        self._pos = jnp.zeros((max_slots,), jnp.int32)
+        self._tok = np.zeros((max_slots, 1, 1), np.int32)
+        self._pos = np.zeros((max_slots,), np.int32)
         self._step_fn = None
 
     # ------------------------------------------------------------- plumbing
@@ -193,8 +210,8 @@ class DecodeBatch:
 
         self._stacked = jax.tree_util.tree_map_with_path(
             write, self._stacked, cache)
-        self._tok = self._tok.at[slot, 0, 0].set(first_token)
-        self._pos = self._pos.at[slot].set(n_tokens)
+        self._tok[slot, 0, 0] = first_token
+        self._pos[slot] = n_tokens
         self.slots[slot] = _Slot(rid=rid, pos=n_tokens, tokens=[first_token],
                                  max_new=max_new)
         return slot
@@ -211,14 +228,15 @@ class DecodeBatch:
         if not self.slots:
             return {}
         live = len(self.slots)
-        # spans: the launch, the wait for its tokens (the loop's first int()
-        # would block on the same value), and the host loop over live slots
+        # spans: the upload and launch, the one read of the next tokens, and
+        # the host bookkeeping over live slots
         with wall_spans.span("repro.decode.launch", live=live):
+            tok, pos = jax.device_put((self._tok, self._pos))
             logits, self._stacked = self._step_fn(
-                self.params, self._stacked, self._tok, self._pos)
-            nxt = jnp.argmax(logits[:, 0, -1], axis=-1).astype(jnp.int32)
-        with wall_spans.span("repro.decode.wait"):
-            nxt.block_until_ready()
+                self.params, self._stacked, tok, pos)
+            nxt = decode_argmax(logits)
+        with wall_spans.span("repro.decode.wait", reads=1):
+            nxt = jax.device_get(nxt)
         out: Dict[int, int] = {}
         with wall_spans.span("repro.decode.slots", live=live):
             for slot, meta in list(self.slots.items()):
@@ -226,8 +244,8 @@ class DecodeBatch:
                 meta.tokens.append(t)
                 meta.pos += 1
                 out[meta.rid] = t
-                self._tok = self._tok.at[slot, 0, 0].set(t)
-                self._pos = self._pos.at[slot].set(meta.pos)
+                self._tok[slot, 0, 0] = t
+                self._pos[slot] = meta.pos
                 if len(meta.tokens) >= meta.max_new \
                         or meta.pos >= self.capacity - 1:
                     self.remove(slot)

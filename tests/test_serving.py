@@ -97,6 +97,17 @@ def test_snapshot_regime_for_ssm():
 
 
 # ------------------------------------------------------- continuous batching
+def _greedy_reference(model, params, prompt, n):
+    """``n`` greedy tokens by teacher-forced full prefill (no decode cache)."""
+    seq, want = list(prompt), []
+    for _ in range(n):
+        lg, _ = model.prefill(
+            params, {"tokens": jnp.asarray(np.asarray(seq)[None], jnp.int32)})
+        want.append(int(jnp.argmax(lg[0, -1])))
+        seq.append(want[-1])
+    return want
+
+
 @pytest.mark.parametrize("arch", ["smollm-360m", "recurrentgemma-9b",
                                   "deepseek-moe-16b"])
 @pytest.mark.slow
@@ -118,17 +129,8 @@ def test_decode_batch_matches_single_sequence(arch):
     while db.n_active:
         for rid, t in db.step().items():
             batched[rid].append(t)
-    # reference: greedy continuation via teacher-forced full prefill
     for rid, p in enumerate(prompts):
-        seq = list(p)
-        want = []
-        for _ in range(3):
-            lg, _ = model.prefill(
-                params, {"tokens": jnp.asarray(np.asarray(seq)[None],
-                                               jnp.int32)})
-            t = int(jnp.argmax(lg[0, -1]))
-            want.append(t)
-            seq.append(t)
+        want = _greedy_reference(model, params, p, 3)
         assert batched[rid][:3] == want, (arch, rid, batched[rid], want)
 
 
@@ -146,6 +148,92 @@ def test_decode_batch_slot_recycling(smollm):
     while db.n_active:
         db.step()
     assert len(db._free) == db.max_slots
+
+
+def test_decode_batch_tokens_match_reference_across_slot_reuse(smollm):
+    """Five requests through three slots, admitted as slots free up between
+    steps, so that a retired slot is reused while the others are
+    mid-sequence: each request's tokens are the per-sequence greedy
+    reference's."""
+    cfg, model, params = smollm
+    eng = ServingEngine(model, params)
+    db = DecodeBatch(model, params, capacity=32, max_slots=3)
+    rng = np.random.default_rng(8)
+    prompts = [rng.integers(0, cfg.vocab, size=(n,))
+               for n in (9, 13, 7, 11, 10)]
+    max_new = [4, 2, 5, 3, 2]
+    got, reused = {}, False
+    waiting, used = list(range(len(prompts))), set()
+    while waiting or db.n_active:
+        while waiting and db.n_active < db.max_slots:
+            rid = waiting.pop(0)
+            t, c, _ = eng.prefill(prompts[rid])
+            slot = db.add(rid, c, len(prompts[rid]), t, max_new=max_new[rid])
+            got[rid] = [t]
+            reused |= slot in used and db.n_active > 1
+            used.add(slot)
+        for rid, t in db.step().items():
+            got[rid].append(t)
+    assert reused
+    for rid, p in enumerate(prompts):
+        assert got[rid] == _greedy_reference(model, params, p,
+                                             max_new[rid]), rid
+
+
+def test_decode_step_uploads_explicitly_and_reads_once(smollm, monkeypatch):
+    """After a warm-up step, a step runs with implicit host-to-device
+    transfers disallowed, and reads the device exactly once whatever the
+    number of live slots."""
+    cfg, model, params = smollm
+    eng = ServingEngine(model, params)
+    db = DecodeBatch(model, params, capacity=32, max_slots=4)
+    rng = np.random.default_rng(9)
+
+    def admit(rid, max_new):
+        p = rng.integers(0, cfg.vocab, size=(8,))
+        t, c, _ = eng.prefill(p)
+        db.add(rid, c, len(p), t, max_new=max_new)
+
+    reads = []
+    device_get = jax.device_get
+
+    def counting_get(x):
+        reads.append(1)
+        return device_get(x)
+
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    admit(0, max_new=8)
+    db.step()                                  # warm-up: compiles the step
+    reads.clear()
+    db.step()
+    assert db.n_active == 1 and len(reads) == 1
+    admit(1, max_new=3)                        # retires on the second step
+    admit(2, max_new=8)
+    db.step()
+    reads.clear()
+    with jax.transfer_guard_host_to_device("disallow"):
+        out = db.step()
+    assert set(out) == {0, 1, 2} and len(reads) == 1
+    assert sorted(s.rid for s in db.slots.values()) == [0, 2]
+
+
+def test_decode_admit_and_step_compile_once(smollm):
+    """After one admission and one step, a later admission (into the cache
+    a step wrote) and step compile nothing: the warm-up before a serving
+    window covers both."""
+    from repro.core.telemetry import wall_spans
+    cfg, model, params = smollm
+    eng = ServingEngine(model, params)
+    db = DecodeBatch(model, params, capacity=32, max_slots=3)
+    rng = np.random.default_rng(10)
+    prompts = [rng.integers(0, cfg.vocab, size=(8,)) for _ in range(3)]
+    for rid, p in enumerate(prompts):
+        t, c, _ = eng.prefill(p)
+        if rid == 1:
+            before = wall_spans.compiles.total().compiles
+        db.add(rid, c, len(p), t, max_new=4)
+        db.step()
+    assert wall_spans.compiles.total().compiles == before
 
 
 # ------------------------------------------------------------- orchestrator
