@@ -2,7 +2,8 @@
 
 Once the window has closed, a sample of the finished requests, drawn from
 the seed and always holding the one with the most served tokens, is run
-through the float32 reference over its prompt and served tokens. At each
+through the float32 reference of the configuration's family
+(``families/<model_type>.py``) over its prompt and served tokens. At each
 served position the reading is how far the served token's reference logit
 lies below the reference's best logit there; the number compared is the
 widest such gap over the sample. The first served token comes from the
@@ -21,7 +22,7 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
-from . import reference
+from . import model
 
 
 @dataclass
@@ -80,12 +81,13 @@ def decide(gap: Optional[float], max_gap: float) -> bool:
 
 def served_gap(dims: Dict, seed: int, s: Sample) -> float:
     """Widest gap of a served token below the reference's best."""
-    ref = reference.logits(dims, seed, s.seqs, s.rows)
+    ref = model.family(dims).logits(dims, seed, s.seqs, s.rows)
     return float(gaps(ref, s.served).max())
 
 
 def control_gap(dims: Dict, seed: int, s: Sample) -> float:
     """Widest gap of the float8 reference's first choice."""
-    ref = reference.logits(dims, seed, s.seqs, s.rows)
-    ctl = reference.logits(dims, seed, s.seqs, s.rows, fp8=True)
+    logits = model.family(dims).logits
+    ref = logits(dims, seed, s.seqs, s.rows)
+    ctl = logits(dims, seed, s.seqs, s.rows, fp8=True)
     return float(gaps(ref, [c.argmax(-1) for c in ctl]).max())

@@ -3,21 +3,22 @@
 Counts use the model's own query heads (not the count padded for
 sharding) and a causal mask, and leave out decode lanes that hold no
 request. ``m`` is ``model.dims`` of a configuration. A matmul of
-``[n, a] x [a, b]`` counts ``2 n a b``.
+``[n, a] x [a, b]`` counts ``2 n a b``. The weight matmuls of a token are
+the family's own count (``families/<model_type>.py``); attention and the
+logits are counted here from the shapes every family gives.
 """
 from __future__ import annotations
 
 from typing import Dict, Tuple
+
+from . import model
 
 BYTES = 2                       # bfloat16 weights, activations and cache
 
 
 def matmul_per_token(m: Dict) -> float:
     """Weight matmuls of all layers for one token (no logits)."""
-    d, h, kv, hd, f = (m["d_model"], m["n_heads"], m["n_kv"],
-                       m["head_dim"], m["d_ff"])
-    per_layer = d * h * hd + 2 * d * kv * hd + h * hd * d + 3 * d * f
-    return 2.0 * per_layer * m["n_layers"]
+    return model.family(m).matmul_per_token(m)
 
 
 def attention_pairs(q_len: int, prefix: int) -> float:

@@ -2,47 +2,36 @@
 
 A configuration file (``configs/<name>.json``) holds the model as it is
 run, under the published config's key names, and the serving deployment
-(``serving``: the ``DisaggConfig`` sizes for one chip). Only the dense GQA
-decoder the program builds is read here (gated SiLU MLP, RMSNorm, rotary
-positions without scaling, no biases); a file that asks for anything else
-is refused, and another family adds its own mapping.
+(``serving``: the ``DisaggConfig`` sizes for one chip). Its ``model_type``
+names its family, ``families/<model_type>.py``, which maps the file to the
+program's ``ArchConfig``, refuses what the program does not build, and
+brings the family's reference and FLOP count (``families/__init__.py``).
+A configuration of a new family adds that module and edits nothing here.
 """
 from __future__ import annotations
 
+import importlib
+from pathlib import Path
 from typing import Any, Dict
 
-DENSE_TYPES = ("llama",)
-#: keys of a published config that change the layer equations, with the
-#: only value the program builds (an absent key means that value)
-BUILT = {"hidden_act": "silu", "mlp_type": "gated_silu",
-         "norm_type": "rms_norm", "use_bias": False, "attention_bias": False,
-         "mlp_bias": False, "rope_scaling": None, "sliding_window": None}
+FAMILIES = Path(__file__).resolve().parent / "families"
+
+
+def family(conf: Dict[str, Any]):
+    """The family module of a configuration, or of its ``dims``."""
+    t = conf["model_type"]
+    if not (FAMILIES / f"{t}.py").is_file():
+        known = sorted(p.stem for p in FAMILIES.glob("*.py")
+                       if p.stem != "__init__")
+        raise ValueError(f"model_type {t!r}: no family"
+                         f" {FAMILIES.name}/{t}.py; families present:"
+                         f" {known}")
+    return importlib.import_module(f"{__package__}.families.{t}")
 
 
 def dims(conf: Dict[str, Any]) -> Dict[str, Any]:
     """The shape of the model, in the reference's own names."""
-    if conf["model_type"] not in DENSE_TYPES:
-        raise ValueError(f"model_type {conf['model_type']!r}: only dense GQA"
-                         f" decoders {DENSE_TYPES} are built here")
-    for k, v in BUILT.items():
-        if conf.get(k, v) != v:
-            raise ValueError(f"{k}={conf[k]!r}: the program builds only"
-                             f" {k}={v!r}")
-    d = conf["hidden_size"]
-    h = conf["num_attention_heads"]
-    return {
-        "d_model": d,
-        "n_layers": conf["num_hidden_layers"],
-        "n_heads": h,
-        "n_kv": conf["num_key_value_heads"],
-        "head_dim": conf.get("head_dim", d // h),
-        "d_ff": conf["intermediate_size"],
-        "vocab": conf["vocab_size"],
-        "tied": bool(conf["tie_word_embeddings"]),
-        "norm_eps": float(conf["rms_norm_eps"]),
-        "rope_theta": float(conf["rope_theta"]),
-        "max_len": int(conf["max_position_embeddings"]),
-    }
+    return family(conf).dims(conf)
 
 
 def shrunk(conf: Dict[str, Any]) -> Dict[str, Any]:
@@ -52,15 +41,7 @@ def shrunk(conf: Dict[str, Any]) -> Dict[str, Any]:
 
 def arch_config(conf: Dict[str, Any]):
     """The program's ``ArchConfig`` for this file."""
-    from repro.configs import ArchConfig
-
-    m = dims(conf)
-    return ArchConfig(
-        name=conf["name"], family="dense", n_layers=m["n_layers"],
-        d_model=m["d_model"], n_heads=m["n_heads"], n_kv=m["n_kv"],
-        d_ff=m["d_ff"], vocab=m["vocab"], head_dim=m["head_dim"],
-        tie_embeddings=m["tied"], norm_eps=m["norm_eps"],
-        rope_theta=m["rope_theta"], source=conf["source"])
+    return family(conf).arch_config(conf)
 
 
 def build_server(conf: Dict[str, Any], params_key, policy: str, hw=None):

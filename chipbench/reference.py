@@ -13,9 +13,12 @@ causal mask, a residual, pre-RMSNorm and a gated SiLU MLP
 (``(silu(x Wg) * (x Wi)) Wo``), a residual; a final RMSNorm and the
 logits (tied: the embedding's transpose). No biases.
 
-This is the published architecture of every configuration the benchmark
-runs (``model.dims`` refuses one that asks for anything else); the query
-heads the program pads are left out here (below).
+It is the reference of the dense decoder family, whose module in
+``families/`` hands its ``logits`` here and whose ``dims`` refuses a
+configuration that asks for anything else; another family brings its own
+reference. The query heads the program pads are left out here (below).
+``params_key``, the seed's key of the served weights, is the program's and
+serves every family.
 
 The weight scheme (what the seed means): ``k = PRNGKey(seed)``, split into
 64; the embedding ``[Vp, d]`` from key 0; an untied unembedding ``[d, Vp]``

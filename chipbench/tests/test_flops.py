@@ -2,8 +2,8 @@
 from chipbench import flops
 
 # one layer: d=8, 2 heads of 4, 1 KV head, MLP width 16, vocab 10
-M = {"d_model": 8, "n_layers": 1, "n_heads": 2, "n_kv": 1, "head_dim": 4,
-     "d_ff": 16, "vocab": 10}
+M = {"model_type": "llama", "d_model": 8, "n_layers": 1, "n_heads": 2,
+     "n_kv": 1, "head_dim": 4, "d_ff": 16, "vocab": 10}
 
 
 def test_matmuls_of_one_token():
