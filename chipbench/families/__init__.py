@@ -19,4 +19,16 @@ A family module provides:
   one token (logits left out).
 
 ``m`` is what ``dims`` returned.
+
+A family also brings its own reference tests,
+``tests/test_family_<model_type>.py``, parametrised over the
+configurations of its ``model_type`` at their ``rehearse`` sizes
+(``tests/test_discovery.py`` refuses a family without them):
+
+* its weights drawn as the served model draws them;
+* its reference logits, through ``model.family(conf).logits``, against
+  the served prefill, within a tolerance of its own and the reason for it
+  (bfloat16 against float32 for the dense decoder; top-k routing that can
+  flip on bfloat16 rounding needs its own argument);
+* its float8 control, off the reference by more than rounding.
 """

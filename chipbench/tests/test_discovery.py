@@ -74,6 +74,10 @@ def matmul_per_token(m):
     return 1234.0
 '''
 
+TOY_CONF = {"name": "toy", "source": "test", "model_type": "toyfam",
+            "vocab": 16, "max_len": 64, "n_layers": 2, "n_heads": 2,
+            "n_kv": 1, "head_dim": 4, "d_model": 8}
+
 TOY_RUN = '''import json
 import numpy as np
 import chipbench
@@ -111,9 +115,7 @@ def test_added_family_is_picked_up(checkout):
     bench_dir = checkout / "chipbench"
     before = _hashes(bench_dir)
     (bench_dir / "families" / "toyfam.py").write_text(TOY_FAMILY)
-    conf = {"name": "toy", "source": "test", "model_type": "toyfam",
-            "vocab": 16, "max_len": 64, "n_layers": 2, "n_heads": 2,
-            "n_kv": 1, "head_dim": 4, "d_model": 8}
+    conf = TOY_CONF
     (bench_dir / "configs" / "toy.json").write_text(json.dumps(conf))
     (bench_dir / "workloads" / "toy.cell.json").write_text(
         (bench_dir / "workloads" / "smollm-360m.code-reuse.json").read_text())
@@ -161,3 +163,93 @@ def test_smollm_dims_are_those_of_the_dense_family():
         "vocab": 49152, "tied": True, "norm_eps": 1e-05,
         "rope_theta": 10000.0, "max_len": 2048}
     assert model.arch_config(conf).family == "dense"
+
+
+def test_every_model_type_brings_its_family_and_tests():
+    """Each ``model_type`` among the configurations has its family module
+    and its own reference tests, ``tests/test_family_<model_type>.py``."""
+    types = {json.loads(p.read_text())["model_type"]
+             for p in (HERE / "configs").glob("*.json")}
+    missing = [str(f.relative_to(HERE)) for t in sorted(types)
+               for f in (HERE / "families" / f"{t}.py",
+                         HERE / "tests" / f"test_family_{t}.py")
+               if not f.is_file()]
+    assert types and not missing, missing
+
+
+TOY_TEST = '''"""The toy family's reference: tokens ranked by id."""
+import json
+
+import numpy as np
+
+from chipbench import model
+from chipbench.spec import HERE
+
+
+def test_reference_ranks_by_id_and_control_reverses():
+    m = model.dims(json.loads((HERE / "configs" / "toy.json").read_text()))
+    logits = model.family(m).logits
+    seqs, rows = [np.arange(5, dtype=np.int32)], [np.array([3, 4])]
+    assert (logits(m, 7, seqs, rows)[0].argmax(-1) == m["vocab"] - 1).all()
+    assert (logits(m, 7, seqs, rows, fp8=True)[0].argmax(-1) == 0).all()
+'''
+
+GUARD = ("chipbench/tests/test_discovery.py::"
+         "test_added_family_brings_its_own_reference_tests")
+
+
+def _pytest(root, *args):
+    """A child pytest over the checkout at ``root`` (the guard left out, so
+    that it does not run itself)."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONDONTWRITEBYTECODE="1", JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join([str(root), str(ROOT / "src")]))
+    return subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "--rootdir", str(root), "--deselect", GUARD, *args],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+
+
+def test_added_family_brings_its_own_reference_tests(tmp_path):
+    """A family, its configuration and its ``test_family_<model_type>.py``
+    come as files only. The benchmark's test files that loop over every
+    configuration pass with them, and so does the family's own file: its
+    reference is judged there, and no other family's file takes a case of
+    it. No file that was there changes. Without its test file the family
+    is refused."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
+    bench_dir = tmp_path / "chipbench"
+    shutil.copytree(HERE, bench_dir,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _hashes(bench_dir)
+    (bench_dir / "families" / "toyfam.py").write_text(TOY_FAMILY)
+    (bench_dir / "configs" / "toy.json").write_text(json.dumps(TOY_CONF))
+    toy_test = bench_dir / "tests" / "test_family_toyfam.py"
+    toy_test.write_text(TOY_TEST)
+
+    # the shared files that loop over every configuration glob configs/;
+    # each other family's file is collected, not run: its cases are its own
+    tests = sorted((bench_dir / "tests").glob("test_*.py"))
+    shared = [str(p.relative_to(tmp_path)) for p in tests
+              if not p.name.startswith("test_family_")
+              and '"configs").glob(' in p.read_text()]
+    families = [str(p.relative_to(tmp_path)) for p in tests
+                if p.name.startswith("test_family_") and p != toy_test]
+    p = _pytest(tmp_path, *shared, str(toy_test.relative_to(tmp_path)))
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+    assert ("test_family_toyfam.py::"
+            "test_reference_ranks_by_id_and_control_reverses PASSED"
+            in p.stdout)
+    p = _pytest(tmp_path, "--collect-only", *families)
+    assert p.returncode == 0, p.stdout[-4000:] + p.stderr[-2000:]
+    assert "[toy]" not in p.stdout, p.stdout
+    assert {p: h for p, h in _hashes(bench_dir).items() if p in before} \
+        == before
+
+    toy_test.unlink()
+    p = _pytest(tmp_path, "chipbench/tests/test_discovery.py::"
+                "test_every_model_type_brings_its_family_and_tests")
+    assert p.returncode == 1, p.stdout[-4000:]
+    assert "tests/test_family_toyfam.py" in p.stdout

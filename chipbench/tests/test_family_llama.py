@@ -1,5 +1,8 @@
-"""The float32 reference draws the served model's weights from the seed
-and computes what the served model computes (CPU, rehearsal sizes)."""
+"""The dense decoder family (``model_type`` ``llama``): its float32
+reference draws the served model's weights from the seed and computes what
+the served model computes (CPU, rehearsal sizes). Each family has a file
+like this one, ``test_family_<model_type>.py``, over its own
+configurations and with its own tolerances."""
 import json
 
 import jax
@@ -10,7 +13,8 @@ import pytest
 from chipbench import model, reference
 from chipbench.spec import HERE
 
-CONFIGS = sorted(p.stem for p in (HERE / "configs").glob("*.json"))
+CONFIGS = sorted(p.stem for p in (HERE / "configs").glob("*.json")
+                 if json.loads(p.read_text())["model_type"] == "llama")
 SEED = 2**31 + 12345
 
 
@@ -53,8 +57,8 @@ def test_logits_match_the_served_prefill(served):
     rng = np.random.default_rng(0)
     seqs = [rng.integers(0, m["vocab"], n).astype(np.int32)
             for n in (37, 80)]
-    ref = reference.logits(m, SEED, seqs, [np.array([len(s) - 1])
-                                           for s in seqs])
+    ref = model.family(m).logits(m, SEED, seqs, [np.array([len(s) - 1])
+                                                 for s in seqs])
     for s, r in zip(seqs, ref):
         got, _ = mdl.prefill(p, {"tokens": jnp.asarray(s[None])})
         got = np.asarray(got[0, -1], np.float32)
@@ -65,10 +69,11 @@ def test_logits_match_the_served_prefill(served):
 
 def test_control_rounds_lower(served):
     m, _, _ = served
+    logits = model.family(m).logits
     rng = np.random.default_rng(1)
     seqs = [rng.integers(0, m["vocab"], 64).astype(np.int32)]
     rows = [np.arange(40, 64)]
-    ref = reference.logits(m, SEED, seqs, rows)[0]
-    ctl = reference.logits(m, SEED, seqs, rows, fp8=True)[0]
+    ref = logits(m, SEED, seqs, rows)[0]
+    ctl = logits(m, SEED, seqs, rows, fp8=True)[0]
     err = np.abs(ctl - ref).max() / np.abs(ref).max()
     assert 1e-3 < err < 0.5, err
